@@ -44,6 +44,9 @@ def main() -> None:
 
     import jax
 
+    from repro.launch import jax_cache
+    jax_cache.enable()
+
     from benchmarks import (bench_multiquery, bench_queries, bench_reads,
                             bench_scaling, bench_serve, bench_throughput,
                             bench_vector, bench_writes)

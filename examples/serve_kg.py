@@ -23,6 +23,7 @@ import numpy as np
 from repro.core.query.executor import QueryCaps
 from repro.core.writes import UpdateVertex
 from repro.data.kg import build_film_kg
+from repro.launch import jax_cache
 from repro.launch.cluster import A1Frontend
 
 
@@ -73,6 +74,7 @@ def main():
     ap.add_argument("--batches", type=int, default=30)
     ap.add_argument("--batch-size", type=int, default=16)
     args = ap.parse_args()
+    jax_cache.enable()
 
     print(f"building KG: {args.films} films / {args.actors} actors ...")
     t0 = time.time()

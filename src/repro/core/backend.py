@@ -112,11 +112,21 @@ def searchsorted_blocked(keys, queries, lo, *, block: int, backend: Backend):
         from repro.kernels.sorted_lookup.kernel import searchsorted_left_ranged
         return searchsorted_left_ranged(keys, queries, lo, lo + block,
                                         interpret=backend.interpret)
-    # reference: per-query dynamic slice + binary search
-    def one(q, l):
-        blk = jax.lax.dynamic_slice(keys, (l,), (block,))
-        return jnp.searchsorted(blk, q, side="left").astype(jnp.int32)
-    return jax.vmap(one)(queries, lo)
+    # reference: per-query binary search inside [lo, lo + block) — no
+    # per-query copy of the block (at a 16M-entry index, 64 probes would
+    # otherwise materialize 4 GiB)
+    n = keys.shape[0]
+    lo = lo.astype(jnp.int32)
+
+    def step(_, lh):
+        a, b = lh
+        mid = (a + b) // 2
+        go = (a < b) & (keys[jnp.minimum(mid, n - 1)] < queries)
+        return jnp.where(go, mid + 1, a), jnp.where(go | (a >= b), b, mid)
+
+    pos, _ = jax.lax.fori_loop(0, int(block).bit_length(), step,
+                               (lo, lo + block))
+    return pos - lo
 
 
 def searchsorted(keys, queries, *, backend: Backend):
@@ -181,8 +191,9 @@ def knn_topk(vecs, emb, gid, vtype, create, delete, q_vt, q_ts, k: int, *,
     (the `Nearest` probe wave).  Entries are filtered by type and MVCC
     visibility per query; ties break by ascending gid, invalid slots come
     back as (+inf, I32MAX).  Both paths are bit-identical — the pallas
-    kernel streams VMEM-resident embedding tiles through a running two-key
-    bitonic top-k merge."""
+    kernel streams the index through VMEM tile by tile into a running
+    two-key bitonic top-k merge, computing each distance with the ref's
+    own f32 operations."""
     if backend.is_pallas:
         from repro.kernels.knn_topk.kernel import knn_topk as _k
         return _k(vecs, emb, gid, vtype, create, delete, q_vt, q_ts, k,

@@ -20,6 +20,7 @@ import jax.numpy as jnp
 
 from repro.core import backend as backend_mod
 from repro.core.addressing import NULL, TS_INF, StoreConfig
+from repro.core.merge import merge_runs
 from repro.core.store import GraphStore, visible
 
 ANY_TYPE = jnp.int32(-1)
@@ -188,32 +189,20 @@ def _compact_one_shard(slot_c, nbr_c, typ_c, cre_c, del_c,      # CSR (cap_e,)
                        gc_ts, cap_v: int):
     """Merge one shard's CSR pool with its delta log; returns new CSR arrays.
 
-    Entries dead at ``gc_ts`` are dropped; survivors sorted by
-    (slot, etype, nbr, create) so future enumerations are contiguous.
+    Entries dead at ``gc_ts`` are dropped; survivors stay sorted by
+    (slot, etype, nbr) so future enumerations are contiguous.  The CSR is
+    already in that order, so the delta is merged in (``core/merge.py``)
+    instead of re-sorting the whole pool.
     """
     cap_e = nbr_c.shape[0]
-    slot_all = jnp.concatenate([slot_c, slot_d])
-    nbr_all = jnp.concatenate([nbr_c, nbr_d])
-    typ_all = jnp.concatenate([typ_c, typ_d])
-    cre_all = jnp.concatenate([cre_c, ts_d])
-    del_all = jnp.concatenate([del_c, del_d])
-
-    live = (nbr_all >= 0) & (del_all > gc_ts)
-    skey = jnp.where(live, slot_all, jnp.int32(cap_v))      # dead sorts last
-    skey, typ_s, nbr_s, cre_s, del_s, slot_s = jax.lax.sort(
-        (skey, typ_all, nbr_all, cre_all, del_all, slot_all), num_keys=3)
-    n_live = jnp.sum(live.astype(jnp.int32))
+    (new_slot, new_typ, new_nbr), (new_cre, new_del), n_live = merge_runs(
+        (slot_c, typ_c, nbr_c), (cre_c, del_c),
+        (nbr_c >= 0) & (del_c > gc_ts),
+        (slot_d, typ_d, nbr_d), (ts_d, del_d),
+        (nbr_d >= 0) & (del_d > gc_ts),
+        cap_e, (cap_v, NULL, NULL, TS_INF, TS_INF))
     overflow = n_live > cap_e
-
-    idx = jnp.arange(cap_e, dtype=jnp.int32)
-    keep = idx < n_live
-    new_nbr = jnp.where(keep, nbr_s[:cap_e], NULL)
-    new_typ = jnp.where(keep, typ_s[:cap_e], NULL)
-    new_cre = jnp.where(keep, cre_s[:cap_e], TS_INF)
-    new_del = jnp.where(keep, del_s[:cap_e], TS_INF)
-    new_slot = jnp.where(keep, skey[:cap_e], cap_v)
-
-    counts = jax.ops.segment_sum(keep.astype(jnp.int32),
+    counts = jax.ops.segment_sum((new_slot < cap_v).astype(jnp.int32),
                                  jnp.minimum(new_slot, cap_v),
                                  num_segments=cap_v + 1)[:cap_v]
     indptr = jnp.concatenate([jnp.zeros((1,), jnp.int32),
@@ -228,8 +217,10 @@ def _slot_of_pool(indptr, cap_e):
 
 
 @partial(jax.jit, static_argnames=("cfg",))
-def compact(store: GraphStore, cfg: StoreConfig, gc_ts) -> GraphStore:
-    """Compact both edge CSRs and the primary index (all shards, vmapped)."""
+def compact(store: GraphStore, cfg: StoreConfig, gc_ts) -> dict:
+    """Compact both edge CSRs (all shards, vmapped): returns the replaced
+    store fields — the compacted CSRs and emptied delta logs — only, so the
+    untouched arrays are neither copied nor held twice."""
     S, cap_v, cap_e, cap_d = cfg.n_shards, cfg.cap_v, cfg.cap_e, cfg.cap_delta
 
     def per_direction(indptr, nbr, typ, cre, dele, dslot, dnbr, dtyp, dts, ddel):
@@ -264,15 +255,7 @@ def compact(store: GraphStore, cfg: StoreConfig, gc_ts) -> GraphStore:
         il_delete=jnp.full((D,), TS_INF), il_count=jnp.zeros((S,), jnp.int32),
     )
 
-    return dataclasses_replace(
-        store,
-        oe_indptr=o_ip, oe_dst=o_nbr, oe_type=o_typ,
-        oe_create=o_cre, oe_delete=o_del,
-        ie_indptr=i_ip, ie_src=i_nbr, ie_type=i_typ,
-        ie_create=i_cre, ie_delete=i_del,
-        **empty_d)
-
-
-def dataclasses_replace(obj, **kw):
-    import dataclasses
-    return dataclasses.replace(obj, **kw)
+    return dict(oe_indptr=o_ip, oe_dst=o_nbr, oe_type=o_typ,
+                oe_create=o_cre, oe_delete=o_del,
+                ie_indptr=i_ip, ie_src=i_nbr, ie_type=i_typ,
+                ie_create=i_cre, ie_delete=i_del, **empty_d)

@@ -15,6 +15,7 @@ from __future__ import annotations
 
 import collections
 import dataclasses
+import functools
 import warnings
 from typing import Optional, Sequence
 
@@ -32,6 +33,15 @@ from repro.core.store import (GraphStore, gather_data, gather_headers,
                               make_store, replay_log_tail)
 from repro.core.writes import CapacityError
 
+_lookup = jax.jit(index_mod.lookup, static_argnames=("cfg", "backend",
+                                                     "xd_win"))
+_headers = jax.jit(gather_headers, static_argnames=("cfg",))
+
+
+@functools.lru_cache(maxsize=None)
+def _placed(fn, sharding):
+    return jax.jit(fn, static_argnames=("cfg",), out_shardings=sharding)
+
 
 class GraphDB:
     """One graph's storage + transactional data plane."""
@@ -39,15 +49,20 @@ class GraphDB:
     def __init__(self, cfg: StoreConfig, *, catalog: Optional[Catalog] = None,
                  tenant: str = "default", graph: str = "g",
                  caps: Optional[txn_mod.BatchCaps] = None,
-                 replication_log=None, backend: Optional[str] = None):
+                 replication_log=None, backend: Optional[str] = None,
+                 sharding=None):
         cfg.validate()
         self.cfg = cfg
+        # placement of the shard-major store arrays across a mesh (None =
+        # the default device); every program that rebuilds store arrays
+        # keeps it, and the ``store`` setter re-places stragglers
+        self.sharding = sharding
         self.caps = caps or txn_mod.BatchCaps()
         # read-path backend ('ref'|'pallas'|'auto'|None = env/auto); resolved
         # by the query executors per call — host conveniences (lookup_vertex,
         # get_edges) always use the cheap jnp reference path
         self.backend = backend
-        self.store: GraphStore = make_store(cfg)
+        self.store: GraphStore = make_store(cfg, sharding)
         self.catalog = catalog or Catalog()
         if tenant not in self.catalog.tenants:
             self.catalog.create_tenant(tenant)
@@ -90,6 +105,26 @@ class GraphDB:
         self.wave_inbox: collections.deque = collections.deque()
         self.applied_rids: collections.OrderedDict = collections.OrderedDict()
         self.fleet_pins: list[int] = []     # frontend-of-record snapshot pins
+
+    @property
+    def store(self) -> GraphStore:
+        return self._store
+
+    @store.setter
+    def store(self, store: GraphStore) -> None:
+        sh = getattr(self, "sharding", None)
+        if sh is not None:              # host-built counters, mostly
+            store = jax.tree.map(
+                lambda a: a if a.sharding == sh else jax.device_put(a, sh),
+                store)
+        self._store = store
+
+    def placed(self, fn):
+        """``fn`` (jitted with a static ``cfg``) with its array outputs
+        placed like the store."""
+        if self.sharding is None:
+            return fn
+        return _placed(fn, self.sharding)
 
     # ------------------------------------------------------------------
     # schema (control plane; each call = its own implicit txn, §3)
@@ -224,14 +259,35 @@ class GraphDB:
     # ------------------------------------------------------------------
     def lookup_vertex(self, vtype: str, key: int, read_ts: Optional[int] = None
                       ) -> tuple[int, bool]:
-        vt = self.vt(vtype)
-        rts = self.clock if read_ts is None else read_ts
-        g, found = index_mod.lookup(
-            self.store, self.cfg,
-            jnp.asarray([vt.type_id], jnp.int32),
-            jnp.asarray([int(key)], jnp.int32),
-            jnp.asarray([True]), jnp.int32(rts))
+        g, found = self.lookup_vertices([self.vt(vtype).type_id], [key],
+                                        read_ts)
         return int(g[0]), bool(found[0])
+
+    def lookup_vertices(self, type_ids, keys, read_ts: Optional[int] = None
+                        ) -> tuple[np.ndarray, np.ndarray]:
+        """Batched primary-index probe: ``(gids, found)`` for each
+        ``(type_id, key)`` at one snapshot — one jitted program per pow2
+        batch size, so staging a write of many creates costs one device
+        round trip, not one per vertex."""
+        n = len(keys)
+        P = 1 << max(0, n - 1).bit_length()
+        pad = lambda xs: np.pad(np.asarray(xs, np.int32), (0, P - n))
+        rts = self.clock if read_ts is None else read_ts
+        g, found = _lookup(self.store, self.cfg, jnp.asarray(pad(type_ids)),
+                           jnp.asarray(pad(keys)), jnp.arange(P) < n,
+                           jnp.int32(rts))
+        return np.asarray(g)[:n], np.asarray(found)[:n]
+
+    def read_headers(self, gids, read_ts: int):
+        """Batched vertex-header read at a snapshot: ``(vtype, key, alive)``
+        host arrays, one device round trip for the whole batch."""
+        n = len(gids)
+        P = 1 << max(0, n - 1).bit_length()
+        g = np.pad(np.asarray(gids, np.int32), (0, P - n), constant_values=-1)
+        vt, key, alive = _headers(self.store, self.cfg, jnp.asarray(g),
+                                  jnp.int32(read_ts))
+        return (np.asarray(vt)[:n], np.asarray(key)[:n],
+                np.asarray(alive)[:n])
 
     def get_vertex(self, vtype: str, key: int) -> Optional[dict]:
         vt = self.vt(vtype)
@@ -324,16 +380,17 @@ class GraphDB:
 
     def run_compaction(self) -> None:
         """Inline (stop-the-world) edge compaction — overflow backstop."""
-        self.store = edges_mod.compact(self.store, self.cfg,
-                                       jnp.int32(self.gc_ts()))
+        self.store = dataclasses.replace(self.store, **self.placed(
+            edges_mod.compact)(self.store, self.cfg, jnp.int32(self.gc_ts())))
         self.dl_count[:] = 0
         self.il_count[:] = 0
         self.stats["compactions"] += 1
         self.epochs["compact_edges"] += 1
 
     def run_index_compaction(self) -> None:
-        self.store = index_mod.compact_index(self.store, self.cfg,
-                                             jnp.int32(self.gc_ts()))
+        self.store = dataclasses.replace(self.store, **self.placed(
+            index_mod.compact_index)(self.store, self.cfg,
+                                     jnp.int32(self.gc_ts())))
         self.xd_count[:] = 0
         self.epochs["compact_index"] += 1
 
@@ -373,19 +430,20 @@ class GraphDB:
 
         Folds the delta logs into base CSR/index at ``gc_ts()`` (respecting
         ``active_query_ts`` pins, §2.2) *without* touching the live store —
-        ``edges.compact``/``index.compact_index`` are pure.  Returns a handle
-        carrying the shadow, the per-shard fill watermarks at build time, and
-        the structural-epoch snapshot that :meth:`try_handoff` validates.
+        ``edges.compact``/``index.compact_index`` are pure and return only
+        the fields they rebuild.  Returns a handle carrying those shadow
+        fields, the per-shard fill watermarks at build time, and the
+        structural-epoch snapshot that :meth:`try_handoff` validates.
         """
         handle = {"gc_ts": self.gc_ts(), "kinds": tuple(kinds),
                   "epochs": dict(self.epochs), "shadow": {}, "marks": {}}
         if "edges" in kinds:
-            handle["shadow"]["edges"] = edges_mod.compact(
+            handle["shadow"]["edges"] = self.placed(edges_mod.compact)(
                 self.store, self.cfg, jnp.int32(handle["gc_ts"]))
             handle["marks"]["dl"] = self.dl_count.copy()
             handle["marks"]["il"] = self.il_count.copy()
         if "index" in kinds:
-            handle["shadow"]["index"] = index_mod.compact_index(
+            handle["shadow"]["index"] = self.placed(index_mod.compact_index)(
                 self.store, self.cfg, jnp.int32(handle["gc_ts"]))
             handle["marks"]["xd"] = self.xd_count.copy()
         # "vindex" builds no shadow: the fold is a cheap host-side prefix
@@ -406,9 +464,8 @@ class GraphDB:
         pin taken after the build is >= the build's ``gc_ts``, so every
         record the fold dropped was already invisible to it.
 
-        Only the shadow's *compacted* fields are read here — the shadow
-        shares its other arrays with a store version that later waves may
-        have donated back to jax.
+        Only the shadow's *compacted* fields are read here: the emptied delta
+        logs are rebuilt by replaying the live log's tail onto them.
 
         Returns ``{kind: bool}``; a ``False`` kind needs a rebuild.
         """
@@ -440,14 +497,14 @@ class GraphDB:
         w_il = jnp.asarray(handle["marks"]["il"], jnp.int32)
         n_dl = jnp.asarray(self.dl_count, jnp.int32)
         n_il = jnp.asarray(self.il_count, jnp.int32)
-        repl = {f: getattr(sh, f) for f in (
+        repl = {f: sh[f] for f in (
             "oe_indptr", "oe_dst", "oe_type", "oe_create", "oe_delete",
             "ie_indptr", "ie_src", "ie_type", "ie_create", "ie_delete")}
         for f in ("dl_slot", "dl_nbr", "dl_type", "dl_create", "dl_delete"):
-            repl[f] = replay_log_tail(getattr(sh, f), getattr(self.store, f),
+            repl[f] = replay_log_tail(sh[f], getattr(self.store, f),
                                       w_dl, n_dl, cap=cap)
         for f in ("il_slot", "il_nbr", "il_type", "il_create", "il_delete"):
-            repl[f] = replay_log_tail(getattr(sh, f), getattr(self.store, f),
+            repl[f] = replay_log_tail(sh[f], getattr(self.store, f),
                                       w_il, n_il, cap=cap)
         self.dl_count -= handle["marks"]["dl"]
         self.il_count -= handle["marks"]["il"]
@@ -462,11 +519,11 @@ class GraphDB:
         cap = self.cfg.cap_idx_delta
         w_xd = jnp.asarray(handle["marks"]["xd"], jnp.int32)
         n_xd = jnp.asarray(self.xd_count, jnp.int32)
-        repl = {f: getattr(sh, f) for f in (
+        repl = {f: sh[f] for f in (
             "ix_vtype", "ix_key", "ix_gid", "ix_create", "ix_delete",
             "ix_count")}
         for f in ("xd_vtype", "xd_key", "xd_gid", "xd_create", "xd_delete"):
-            repl[f] = replay_log_tail(getattr(sh, f), getattr(self.store, f),
+            repl[f] = replay_log_tail(sh[f], getattr(self.store, f),
                                       w_xd, n_xd, cap=cap)
         self.xd_count -= handle["marks"]["xd"]
         repl["xd_count"] = jnp.asarray(self.xd_count, jnp.int32)
@@ -526,9 +583,7 @@ class GraphDB:
         return f, i
 
     def _read_header_host(self, gid: int, rts: int):
-        vt, key, alive = gather_headers(
-            self.store, self.cfg, jnp.asarray([gid], jnp.int32),
-            jnp.int32(rts))
+        vt, key, alive = self.read_headers([gid], rts)
         return int(vt[0]), int(key[0]), bool(alive[0])
 
     def _read_data_host(self, gid: int, rts: int):

@@ -22,6 +22,7 @@ import numpy as np
 
 from repro.core import backend as backend_mod
 from repro.core.addressing import NULL, TS_INF, StoreConfig
+from repro.core.merge import merge_runs
 from repro.core.store import GraphStore, visible, window_shard_major
 
 _C1 = np.int32(-1640531527)   # 2654435769: Knuth multiplicative
@@ -136,33 +137,27 @@ def lookup(store: GraphStore, cfg: StoreConfig, vtypes, keys, valid, read_ts,
 
 
 @partial(jax.jit, static_argnames=("cfg",))
-def compact_index(store: GraphStore, cfg: StoreConfig, gc_ts) -> GraphStore:
-    """Merge the index delta into the sorted main index (all shards)."""
-    import dataclasses
+def compact_index(store: GraphStore, cfg: StoreConfig, gc_ts) -> dict:
+    """Merge the index delta into the sorted main index (all shards).
+
+    The main index is sorted by (mix32 hash, vtype, key), so the delta is
+    merged in (``core/merge.py``) rather than re-sorting the whole index.
+    Returns the replaced store fields only."""
     S, cap_x, cap_xd = cfg.n_shards, cfg.cap_idx, cfg.cap_idx_delta
 
-    def one(vt_m, k_m, g_m, c_m, d_m, vt_d, k_d, g_d, c_d, d_d):
-        vt = jnp.concatenate([vt_m, vt_d])
-        k = jnp.concatenate([k_m, k_d])
-        g = jnp.concatenate([g_m, g_d])
-        c = jnp.concatenate([c_m, c_d])
-        d = jnp.concatenate([d_m, d_d])
-        live = (g >= 0) & (d > gc_ts)
-        h = jnp.where(live, mix32(vt, k), jnp.int32(2**31 - 1))
-        h_s, vt_s, k_s, g_s, c_s, d_s = jax.lax.sort(
-            (h, vt, k, g, c, d), num_keys=3)
-        n_live = jnp.sum(live.astype(jnp.int32))
-        idx = jnp.arange(cap_x, dtype=jnp.int32)
-        keep = idx < n_live
-        return (jnp.where(keep, vt_s[:cap_x], TS_INF),
-                jnp.where(keep, k_s[:cap_x], TS_INF),
-                jnp.where(keep, g_s[:cap_x], NULL),
-                jnp.where(keep, c_s[:cap_x], TS_INF),
-                jnp.where(keep, d_s[:cap_x], TS_INF),
-                n_live, n_live > cap_x)
+    def hashed(vt, k, g):
+        return jnp.where(g >= 0, mix32(vt, k), jnp.int32(2**31 - 1))
 
-    fn = jax.vmap(one)
-    vt, k, g, c, d, n, ovf = fn(
+    def one(vt_m, k_m, g_m, c_m, d_m, vt_d, k_d, g_d, c_d, d_d):
+        (_, vt, k), (g, c, d), n_live = merge_runs(
+            (hashed(vt_m, k_m, g_m), vt_m, k_m), (g_m, c_m, d_m),
+            (g_m >= 0) & (d_m > gc_ts),
+            (hashed(vt_d, k_d, g_d), vt_d, k_d), (g_d, c_d, d_d),
+            (g_d >= 0) & (d_d > gc_ts),
+            cap_x, (2**31 - 1, TS_INF, TS_INF, NULL, TS_INF, TS_INF))
+        return vt, k, g, c, d, n_live
+
+    vt, k, g, c, d, n = jax.vmap(one)(
         store.ix_vtype.reshape(S, cap_x), store.ix_key.reshape(S, cap_x),
         store.ix_gid.reshape(S, cap_x), store.ix_create.reshape(S, cap_x),
         store.ix_delete.reshape(S, cap_x),
@@ -171,8 +166,7 @@ def compact_index(store: GraphStore, cfg: StoreConfig, gc_ts) -> GraphStore:
         store.xd_delete.reshape(S, cap_xd))
 
     XD = S * cap_xd
-    return dataclasses.replace(
-        store,
+    return dict(
         ix_vtype=vt.reshape(-1), ix_key=k.reshape(-1), ix_gid=g.reshape(-1),
         ix_create=c.reshape(-1), ix_delete=d.reshape(-1),
         ix_count=n.astype(jnp.int32),

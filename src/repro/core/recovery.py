@@ -142,6 +142,7 @@ def _wire_db(s: dict, store) -> GraphDB:
     :func:`attach_shared`)."""
     db = GraphDB.__new__(GraphDB)
     db.cfg = s["cfg"]
+    db.sharding = None
     db.caps = __import__("repro.core.txn", fromlist=["BatchCaps"]
                          ).BatchCaps()
     db.store = store

@@ -112,8 +112,14 @@ def _full(shape, fill, dtype=jnp.int32):
     return jnp.full(shape, fill, dtype=dtype)
 
 
-def make_store(cfg: StoreConfig) -> GraphStore:
-    """Allocate an empty store (all device arrays)."""
+def make_store(cfg: StoreConfig, sharding=None) -> GraphStore:
+    """Allocate an empty store (all device arrays).
+
+    ``sharding`` (e.g. ``NamedSharding(mesh, P(axes))``) places every
+    shard-major array across the mesh as it is created, so no single
+    device ever holds the whole store."""
+    if sharding is not None:
+        return jax.jit(partial(make_store, cfg), out_shardings=sharding)()
     S = cfg.n_shards
     V, E, D, X, XD = (S * cfg.cap_v, S * cfg.cap_e, S * cfg.cap_delta,
                       S * cfg.cap_idx, S * cfg.cap_idx_delta)

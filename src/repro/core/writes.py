@@ -122,17 +122,22 @@ class WriteResult:
 # Staging: op record -> Transaction (the wrappers' logic, shared)
 # ---------------------------------------------------------------------------
 
-def stage(db, op: WriteOp, t) -> int:
+def stage(db, op: WriteOp, t, exists: Optional[bool] = None) -> int:
     """Stage one mutation-op record into an open transaction.
 
     Performs the record's read-validate round-trips at ``t.read_ts`` (reads
     recorded for OCC), raises ``ValueError`` on contract violations exactly
     as the historical per-op methods did, and returns the allocated gid for
-    ``CreateVertex`` (−1 for every other kind).
+    ``CreateVertex`` (−1 for every other kind).  ``exists`` is a
+    ``CreateVertex``'s index probe when the caller batched it
+    (:func:`_stage_all`).
     """
     if isinstance(op, CreateVertex):
         vt = db.vt(op.vtype)
-        g, found = db.lookup_vertex(op.vtype, int(op.key), read_ts=t.read_ts)
+        found = exists
+        if found is None:
+            _, found = db.lookup_vertex(op.vtype, int(op.key),
+                                        read_ts=t.read_ts)
         if found:
             raise ValueError(f"vertex ({op.vtype}, {op.key}) already exists")
         f, i = db._encode_attrs(vt, op.attrs or {})
@@ -249,20 +254,20 @@ def _validate_program(cfg, P: int):
     return fn
 
 
-def _apply_program(cfg, shapes: tuple):
+def _apply_program(cfg, shapes: tuple, sharding=None):
     """The fused apply program of one mutation-shape group.
 
     ``shapes`` is the canonical ``(create_v, update_v, delete_v, create_e,
     delete_e)`` pow2 bucket tuple; each distinct tuple traces (and donates
     through) its own jitted instance so LRU eviction actually frees the
-    trace.
+    trace.  ``sharding`` keeps a mesh-placed store where it is.
     """
-    key = ("apply", cfg, shapes)
+    key = ("apply", cfg, shapes, sharding)
     fn = _cache_get(key)
     if fn is None:
         fn = jax.jit(lambda store, ts, *ops:
                      txn_mod.apply_batch_impl(store, cfg, ts, *ops),
-                     donate_argnums=(0,))
+                     donate_argnums=(0,), out_shardings=sharding)
         _cache_put(key, fn)
     return fn
 
@@ -392,7 +397,7 @@ def _apply_chunk(db, chunk, ts: int) -> None:
     """Apply one winner chunk at commit timestamp ``ts`` (the fused
     program dispatch + host bookkeeping shared by commit and replay)."""
     shapes, args = _build_wave(db, chunk)
-    fn = _apply_program(db.cfg, shapes)
+    fn = _apply_program(db.cfg, shapes, db.sharding)
     db.store = fn(db.store, jnp.int32(ts), *args)
     db.clock = max(db.clock, ts)
     if db._vindexed:
@@ -423,11 +428,22 @@ def _remember_rids(db, chunk, ts: int) -> None:
 # Wave records: the unit of fleet replication (§4)
 # ---------------------------------------------------------------------------
 
-def _edge_ident(db, gid: int, ts: int) -> tuple:
-    vt, key, alive = db._read_header_host(gid, ts)
-    if not alive:                   # deleted in the same batch: pre-state
-        vt, key, _ = db._read_header_host(gid, ts - 1)
-    return int(vt), int(key)
+def _idents(db, chunk, ts: int) -> dict:
+    """gid -> (vtype, key) of every vertex a chunk's record names, in two
+    batched header reads: at ``ts``, then at ``ts - 1`` for vertices the
+    chunk itself deleted (their pre-state)."""
+    gids = sorted({int(g) for t in chunk for g in
+                   [x[0] for x in t.update_v]
+                   + [v for e in t.create_e + t.delete_e for v in e[:2]]})
+    if not gids:
+        return {}
+    vt, key, alive = db.read_headers(gids, ts)
+    dead = [g for g, a in zip(gids, alive) if not a]
+    out = {g: (int(v), int(k)) for g, v, k in zip(gids, vt, key)}
+    if dead:
+        vt, key, _ = db.read_headers(dead, ts - 1)
+        out.update({g: (int(v), int(k)) for g, v, k in zip(dead, vt, key)})
+    return out
 
 
 def wave_record(db, chunk, ts: int, seq: int) -> dict:
@@ -439,11 +455,12 @@ def wave_record(db, chunk, ts: int, seq: int) -> dict:
     edge endpoints), so a db-less consumer (the frontend's durable
     :class:`~repro.core.replication.ReplicationLog`) can derive the
     logical log entries without a store to read headers from."""
+    ident = _idents(db, chunk, ts)
     txns = []
     for t in chunk:
         uv = []
         for gid, f, i in t.update_v:
-            vt, key, _ = db._read_header_host(gid, ts)
+            vt, key = ident[int(gid)]
             uv.append([int(gid), int(vt), int(key),
                        np.asarray(f).tolist(), np.asarray(i).tolist()])
         txns.append({
@@ -455,10 +472,10 @@ def wave_record(db, chunk, ts: int, seq: int) -> dict:
             "delete_v": [[int(g), int(vt), int(k)]
                          for g, vt, k in t.delete_v],
             "create_e": [[int(s), int(d), int(et),
-                          *_edge_ident(db, s, ts), *_edge_ident(db, d, ts)]
+                          *ident[int(s)], *ident[int(d)]]
                          for s, d, et in t.create_e],
             "delete_e": [[int(s), int(d), int(et),
-                          *_edge_ident(db, s, ts), *_edge_ident(db, d, ts)]
+                          *ident[int(s)], *ident[int(d)]]
                          for s, d, et in t.delete_e],
         })
     return {"seq": int(seq), "ts": int(ts),
@@ -620,6 +637,20 @@ def _build_wave(db, chunk):
 # The entry point (exported as GraphDB.write)
 # ---------------------------------------------------------------------------
 
+def _stage_all(db, ops, t) -> list:
+    """Stage op records in order, with every ``CreateVertex``'s existence
+    probe batched into one index lookup at the transaction's snapshot (the
+    probes read committed state only, so batching them changes nothing)."""
+    cv = [op for op in ops if isinstance(op, CreateVertex)]
+    found = iter(())
+    if cv:
+        _, f = db.lookup_vertices([db.vt(op.vtype).type_id for op in cv],
+                                  [int(op.key) for op in cv], t.read_ts)
+        found = iter(bool(x) for x in f)
+    return [stage(db, op, t, next(found) if isinstance(op, CreateVertex)
+                  else None) for op in ops]
+
+
 def write(db, ops, *, txn=None, caps=None) -> WriteResult:
     """Execute a batch of mutations (see ``GraphDB.write`` for the API doc).
 
@@ -647,13 +678,13 @@ def write(db, ops, *, txn=None, caps=None) -> WriteResult:
             raise TypeError(f"not a mutation-op record: {type(op).__name__}")
     if txn is not None:
         t, _ = db._txn(txn)
-        gids = [stage(db, op, t) for op in ops]
+        gids = _stage_all(db, ops, t)
         return WriteResult(statuses=["STAGED"] * len(ops), gids=gids,
                            reasons=[None] * len(ops), ts=-1)
     # implicit transaction: the whole op list commits atomically (§3's
     # "a transaction is implicitly created for that operation", batched)
     t = db.create_transaction()
-    gids = [stage(db, op, t) for op in ops]
+    gids = _stage_all(db, ops, t)
     statuses, reasons = commit_wave(db, [t], caps)
     committed = statuses[0] == "COMMITTED"
     return WriteResult(

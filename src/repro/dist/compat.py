@@ -1,46 +1,26 @@
-"""jax API drift shims.
+"""The jax surface every mesh program goes through.
 
-The repo targets the current jax surface (``jax.shard_map`` with
-``check_vma``, ``jax.make_mesh(..., axis_types=...)``); the pinned
-toolchain ships 0.4.37 where those live under ``jax.experimental`` with
-older spellings.  Every call site goes through this module so the drift is
-handled exactly once.
+The repo runs on jax 0.9: ``jax.shard_map`` with ``check_vma``, and
+``jax.make_mesh``, whose axes default to Explicit there.  Sharding
+constraints and shard_map programs in this repo assume Auto axes, so every
+mesh is built here.
 """
 from __future__ import annotations
 
 import jax
-
-try:                                    # jax >= 0.5
-    from jax.sharding import AxisType as _AxisType
-except ImportError:                     # 0.4.x
-    _AxisType = None
+from jax.sharding import AxisType
 
 
-if hasattr(jax, "shard_map"):
-    def shard_map(f, *, mesh, in_specs, out_specs, check_vma: bool = True):
-        return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
-                             out_specs=out_specs, check_vma=check_vma)
-else:
-    from jax.experimental.shard_map import shard_map as _shard_map_legacy
-
-    def shard_map(f, *, mesh, in_specs, out_specs, check_vma: bool = True):
-        # pre-0.5 spelling: check_vma was called check_rep
-        return _shard_map_legacy(f, mesh=mesh, in_specs=in_specs,
-                                 out_specs=out_specs, check_rep=check_vma)
+def shard_map(f, *, mesh, in_specs, out_specs, check_vma: bool = True):
+    return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
+                         out_specs=out_specs, check_vma=check_vma)
 
 
-if hasattr(jax.lax, "axis_size"):
-    def axis_size(axis_name):
-        return jax.lax.axis_size(axis_name)
-else:
-    def axis_size(axis_name):
-        # psum of a static 1 constant-folds to the (static) axis size
-        return jax.lax.psum(1, axis_name)
+def axis_size(axis_name):
+    return jax.lax.axis_size(axis_name)
 
 
 def make_mesh(axis_shapes, axis_names):
-    """``jax.make_mesh`` with Auto axis types where the API supports them."""
-    if _AxisType is not None:
-        return jax.make_mesh(axis_shapes, axis_names,
-                             axis_types=(_AxisType.Auto,) * len(axis_names))
-    return jax.make_mesh(axis_shapes, axis_names)
+    """``jax.make_mesh`` with Auto axis types."""
+    return jax.make_mesh(axis_shapes, axis_names,
+                         axis_types=(AxisType.Auto,) * len(axis_names))

@@ -6,10 +6,16 @@ span in FaRM.  The TPU adaptation streams those spans tile-by-tile:
 * a host/jnp *plan* (ref.plan) flattens the ragged spans into a dense grid of
   128-lane tiles: tile i serves frontier item ``item_of_tile[i]``, its
   ``tw``-th tile;
-* scalar-prefetched span starts feed the BlockSpec index_map, so the Pallas
-  pipeline DMA-streams the right edge-pool tiles (two adjacent tiles per
-  step, because spans are not tile-aligned);
-* the kernel rotates the 2-tile window to the span offset and masks the tail.
+* each pool is viewed as (rows, 128) so every DMA moves whole (8, 128)
+  VMEM tiles; a scalar-prefetched per-tile row drives the BlockSpec
+  index_map, which streams the 8-row group holding the tile's first edge
+  and the group after it (spans are not tile-aligned);
+* the kernel rotates the 16-row window to the span offset (``pltpu.roll``),
+  picks the two rows the tile straddles, and masks the tail.
+
+One grid step emits one tile into row ``t % 8`` of an (8, 128) output block
+that stays resident for eight consecutive steps, so every output store is a
+whole aligned block.
 
 Output is tile-padded ragged: lane j of tile i is edge ``tw*T + j`` of item
 ``item_of_tile[i]``, or -1.  Downstream (dedup/routing) consumes the mask.
@@ -27,64 +33,98 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+LANES = 128
+SUB = 8          # sublanes per (8, 128) i32 tile
+# tiles per pallas_call: its two prefetched (CHUNK,) i32 tables must fit the
+# 1 MiB scalar memory, so larger plans run as several calls
+CHUNK = 1 << 16
 
-def _expand_kernel(item_ref, tw_ref, starts_ref, degs_ref,   # scalar prefetch
-                   *refs, tile: int, n_pools: int, F: int):
+
+def _pick_row(win, r):
+    """Row ``r`` (traced) of a (16, L) window, as (1, L): exact masked sum
+    (one nonzero term per lane), which needs no dynamic sublane slice."""
+    rows = jax.lax.broadcasted_iota(jnp.int32, win.shape, 0)
+    return jnp.sum(jnp.where(rows == r, win, 0), axis=0, keepdims=True)
+
+
+def _expand_kernel(row_ref, meta_ref, *refs, n_pools: int):
     t = pl.program_id(0)
-    in_refs = refs[:2 * n_pools]
-    out_refs = refs[2 * n_pools:]
-    item = item_ref[t]
-    tw = tw_ref[t]
-    item_c = jnp.minimum(item, F - 1)
-    start = starts_ref[item_c] + tw * tile
-    off = start % tile
-    lane = jax.lax.iota(jnp.int32, tile)
-    valid = (item < F) & (lane < degs_ref[item_c] - tw * tile)
+    in_refs, out_refs = refs[:2 * n_pools], refs[2 * n_pools:]
+    r = row_ref[t] % SUB                 # tile's first row within its group
+    off = meta_ref[t] // (2 * LANES)     # span offset within that row
+    n_ok = meta_ref[t] % (2 * LANES)     # valid lanes of this tile
+    lane = jax.lax.broadcasted_iota(jnp.int32, (1, LANES), 1)
+    sub = jax.lax.broadcasted_iota(jnp.int32, (SUB, LANES), 0)
+    from_lo = lane < LANES - off
+    valid = lane < n_ok
+    shift = (LANES - off) % LANES
+
+    @pl.when(t % SUB == 0)
+    def _init():
+        for o in out_refs:
+            o[...] = jnp.full((SUB, LANES), -1, jnp.int32)
+
     for p in range(n_pools):
-        lo = in_refs[2 * p][...]
-        hi = in_refs[2 * p + 1][...]
-        window = jnp.roll(jnp.concatenate([lo, hi]), -off)[:tile]
-        out_refs[p][...] = jnp.where(valid, window, -1)[None, :]
+        win = jnp.concatenate([in_refs[2 * p][...], in_refs[2 * p + 1][...]],
+                              axis=0)                        # (16, 128)
+        win = pltpu.roll(win, shift, 1)  # lane c of row q -> edge q*128+c+off
+        row = jnp.where(from_lo, _pick_row(win, r), _pick_row(win, r + 1))
+        row = jnp.where(valid, row, -1)
+        o = out_refs[p]
+        o[...] = jnp.where(sub == t % SUB, row, o[...])
 
 
+@functools.partial(jax.jit, static_argnames=("tile", "cap_tiles", "interpret"))
 def expand(starts, degs, pools, item_of_tile, tw_of_tile, *, tile: int = 128,
            cap_tiles: int, interpret: bool = False):
     """See ref.expand; plan arrays are produced by ref.plan (jnp, cheap)."""
+    if tile != LANES:
+        raise ValueError(f"edge_expand streams {LANES}-lane tiles, got {tile}")
     F = degs.shape[0]
     E = pools[0].shape[0]
     n_pools = len(pools)
-    # pad pools by two tiles so the +1 block fetch never leaves the array
-    pools_p = tuple(jnp.pad(p, (0, 2 * tile), constant_values=-1)
-                    for p in pools)
-    n_blocks = (E + 2 * tile) // tile
+    # per-tile scalars: absolute pool row, and (span offset, valid lanes)
+    item_c = jnp.minimum(item_of_tile, F - 1)
+    start = starts[item_c] + tw_of_tile * LANES
+    n_ok = jnp.where(item_of_tile < F,
+                     jnp.clip(degs[item_c] - tw_of_tile * LANES, 0, LANES), 0)
+    start = jnp.where(n_ok > 0, start, 0)
+    ct8 = pl.cdiv(cap_tiles, SUB) * SUB
+    row = jnp.pad(start // LANES, (0, ct8 - cap_tiles))
+    meta = jnp.pad((start % LANES) * (2 * LANES) + n_ok, (0, ct8 - cap_tiles))
 
-    def mk_in_spec(plus_one):
-        def index_map(t, item_ref, tw_ref, starts_ref, degs_ref):
-            item = jnp.minimum(item_ref[t], F - 1)
-            blk = (starts_ref[item] + tw_ref[t] * tile) // tile
-            return (jnp.minimum(blk + plus_one, n_blocks - 1),)
-        return pl.BlockSpec((tile,), index_map)
+    rows = pl.cdiv(E, LANES)
+    n_grp = pl.cdiv(rows, SUB)
+    pools2 = tuple(p.reshape(rows, LANES) if E == rows * LANES else
+                   jnp.pad(p, (0, rows * LANES - E),
+                           constant_values=-1).reshape(rows, LANES)
+                   for p in pools)
 
-    in_specs = []
-    for _ in range(n_pools):
-        in_specs.append(mk_in_spec(0))
-        in_specs.append(mk_in_spec(1))
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=4,
-        grid=(cap_tiles,),
-        in_specs=in_specs,
-        out_specs=[pl.BlockSpec((1, tile), lambda t, *_: (t, 0))
-                   for _ in range(n_pools)],
-    )
-    # inputs interleaved: each pool appears twice (tile t and t+1)
-    args = []
-    for p in pools_p:
-        args += [p, p]
-    outs = pl.pallas_call(
-        functools.partial(_expand_kernel, tile=tile, n_pools=n_pools, F=F),
-        grid_spec=grid_spec,
-        out_shape=[jax.ShapeDtypeStruct((cap_tiles, tile), jnp.int32)
+    def spec(plus_one):
+        def index_map(t, row_ref, meta_ref):
+            g = row_ref[t] // SUB + plus_one
+            return (jnp.minimum(g, n_grp - 1), 0)
+        return pl.BlockSpec((SUB, LANES), index_map)
+
+    call = pl.pallas_call(
+        functools.partial(_expand_kernel, n_pools=n_pools),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=2,
+            grid=(min(ct8, CHUNK),),
+            in_specs=[spec(k) for _ in range(n_pools) for k in (0, 1)],
+            out_specs=[pl.BlockSpec((SUB, LANES), lambda t, *_: (t // SUB, 0))
+                       for _ in range(n_pools)]),
+        out_shape=[jax.ShapeDtypeStruct((min(ct8, CHUNK), LANES), jnp.int32)
                    for _ in range(n_pools)],
         interpret=interpret,
-    )(item_of_tile, tw_of_tile, starts, degs, *args)
-    return tuple(o.reshape(-1) for o in outs)
+    )
+    args = [p for p in pools2 for _ in (0, 1)]
+    parts = []
+    for c0 in range(0, ct8, CHUNK):
+        n = min(CHUNK, ct8 - c0)
+        r_c = jnp.pad(row[c0:c0 + n], (0, min(ct8, CHUNK) - n))
+        m_c = jnp.pad(meta[c0:c0 + n], (0, min(ct8, CHUNK) - n))
+        parts.append([o[:n] for o in call(r_c, m_c, *args)])
+    outs = [p[0] if len(parts) == 1 else jnp.concatenate(p)
+            for p in zip(*parts)]
+    return tuple(o[:cap_tiles].reshape(-1) for o in outs)

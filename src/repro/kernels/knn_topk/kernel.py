@@ -2,23 +2,23 @@
 
 Hardware adaptation: the reference path materializes the full (R, N)
 distance matrix in HBM and runs an XLA two-key sort over its whole width.
-Here the embedding block stays resident in VMEM and each query row block
-streams over it in tiles of 128 entries: one MXU matmul produces the
-(br, 128) distance tile, MVCC + type visibility is masked in-register, and
-the tile is merged into a running per-query top-KP buffer with a two-key
-(dist, gid) bitonic network — the same compare-exchange idiom as
-``dedup_compact``, with a float primary key.  The full-width distance
-matrix never exists.
+Here the index streams through VMEM in tiles of BN entries on the inner
+grid axis: each step computes the (br, BN) distance tile, masks MVCC +
+type visibility in-register, and — only when some visible entry of the
+tile can still enter the answer — merges the tile into a running per-query
+top-KB buffer kept in VMEM scratch across the whole N axis, with the
+rotate+select bitonic network of ``kernels/bitonic.py`` on a (dist, gid)
+key pair.  The full-width distance matrix never exists, and VMEM holds one
+tile whatever the index size.
 
-Bit-parity with the ref oracle: every distance is an independent
-``||e||^2 - 2<v, e>`` dot over the (zero-padded) feature axis, so tiling N
-cannot change any value; selection then orders identical (dist, gid) pairs
-lexicographically, which has exactly one answer.  ``+ 0.0`` canonicalizes
--0.0 on both paths so the sort sees identical bit patterns.
+Bit-parity with the ref oracle: both paths compute every distance with the
+same f32 operations in the same order (``ref.sq_norms`` for ``||e||^2``,
+then ``<v, e>`` accumulated one feature at a time, then
+``(||e||^2 - 2<v, e>) + 0.0``), so tiling cannot change any value;
+selection then orders identical (dist, gid) pairs lexicographically, which
+has exactly one answer.  ``+ 0.0`` canonicalizes -0.0 on both paths.
 
-Grid: (row_blocks,); the padded embedding block (N2, D2) plus per-entry
-metadata lives in VMEM per program — at index caps (N ~ 8K, D <= 128 this
-repro) that is ~4MB, well under budget.
+Grid: (row_blocks, entry_tiles); the entry axis is sequential.
 """
 from __future__ import annotations
 
@@ -27,138 +27,93 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
+
+from repro.kernels.bitonic import LANES, SUB, sort_refs
+from repro.kernels.knn_topk.ref import inner_products, sq_norms
 
 I32MAX = 2**31 - 1
-BN = 128  # entry-tile width (MXU lane width)
+MERGE_W = 1024      # widest merge: lanes of the running buffer + one tile
 
 
 def _pow2ceil(n: int) -> int:
     return 1 << max(0, int(n) - 1).bit_length()
 
 
-def _stages(W: int):
-    out = []
-    k = 2
-    while k <= W:
-        j = k // 2
-        while j >= 1:
-            out.append((k, j))
-            j //= 2
-        k *= 2
-    return out
+def _knn_kernel(v_ref, e_ref, m_ref, qvt_ref, qts_ref, od_ref, og_ref,
+                dbuf, gbuf, *, kp: int, kb: int):
+    n = pl.program_id(1)
+
+    @pl.when(n == 0)
+    def _init():
+        dbuf[...] = jnp.full(dbuf.shape, jnp.inf, jnp.float32)
+        gbuf[...] = jnp.full(gbuf.shape, I32MAX, jnp.int32)
+
+    meta = m_ref[...]                    # (8, BN): gid, vtype, create, delete, ee
+    gid, vt, cr, dl = (meta[i:i + 1, :] for i in range(4))
+    ee = jax.lax.bitcast_convert_type(meta[4:5, :], jnp.float32)
+    ip = inner_products(v_ref[...], e_ref[...])           # (br, BN)
+    qvt, qts = qvt_ref[...], qts_ref[...]                 # (br, 1)
+    ok = (gid >= 0) & (vt == qvt) & (cr <= qts) & (qts < dl)
+    d = jnp.where(ok, (ee - 2.0 * ip) + 0.0, jnp.inf)
+    kth = dbuf[:, kp - 1:kp]                              # current k-th best
+    hit = jnp.max(jnp.where(ok & (d <= kth), 1, 0)) > 0
+
+    @pl.when(hit)
+    def _merge():
+        dbuf[:, kb:] = d
+        gbuf[:, kb:] = jnp.where(ok, jnp.broadcast_to(gid, ok.shape), I32MAX)
+        sort_refs([dbuf, gbuf], flat=False)
+
+    @pl.when(n == pl.num_programs(1) - 1)
+    def _done():
+        od_ref[...] = dbuf[:, :kb]
+        og_ref[...] = gbuf[:, :kb]
 
 
-def _partner(x, j):
-    R, W = x.shape
-    xr = x.reshape(R, W // (2 * j), 2, j)
-    return xr[:, :, ::-1, :].reshape(R, W)
-
-
-def _bitonic_fpairs(d, g, idx):
-    """Two-key (f32 dist, i32 gid) bitonic ascending sort along axis 1."""
-    W = d.shape[1]
-    for k, j in _stages(W):
-        pd, pg = _partner(d, j), _partner(g, j)
-        le = (d < pd) | ((d == pd) & (g <= pg))     # self <= partner
-        is_lower = (idx & j) == 0
-        up = (idx & k) == 0
-        keep_self = le == (is_lower == up)
-        d = jnp.where(keep_self, d, pd)
-        g = jnp.where(keep_self, g, pg)
-    return d, g
-
-
-def _knn_kernel(v_ref, e_ref, ee_ref, g_ref, vt_ref, cr_ref, dl_ref,
-                qvt_ref, qts_ref, od_ref, og_ref, *,
-                kp: int, bn: int, nt: int, d2: int):
-    v = v_ref[...]                       # (br, D2) query block
-    emb = e_ref[...]                     # (N2, D2) resident embedding block
-    ee = ee_ref[...]                     # (1, N2)
-    gid = g_ref[...]                     # (1, N2)
-    vt = vt_ref[...]
-    cr = cr_ref[...]
-    dl = dl_ref[...]
-    qvt = qvt_ref[...]                   # (br, 1)
-    qts = qts_ref[...]                   # (br, 1)
-    br = v.shape[0]
-
-    W2 = _pow2ceil(kp + bn)
-    idx = jax.lax.broadcasted_iota(jnp.int32, (br, W2), 1)
-    INF = jnp.float32(jnp.inf)
-
-    def tile(t, carry):
-        d_buf, g_buf = carry
-        e_t = jax.lax.dynamic_slice(emb, (t * bn, 0), (bn, d2))
-        ee_t = jax.lax.dynamic_slice(ee, (0, t * bn), (1, bn))
-        g_t = jax.lax.dynamic_slice(gid, (0, t * bn), (1, bn))
-        vt_t = jax.lax.dynamic_slice(vt, (0, t * bn), (1, bn))
-        cr_t = jax.lax.dynamic_slice(cr, (0, t * bn), (1, bn))
-        dl_t = jax.lax.dynamic_slice(dl, (0, t * bn), (1, bn))
-        ip = jnp.dot(v, e_t.T, preferred_element_type=jnp.float32)  # (br, bn)
-        ok = (g_t >= 0) & (vt_t == qvt) & (cr_t <= qts) & (qts < dl_t)
-        d = jnp.where(ok, (ee_t - 2.0 * ip) + 0.0, INF)
-        g = jnp.where(ok, jnp.broadcast_to(g_t, ok.shape), I32MAX)
-        cd = jnp.concatenate([d_buf, d], axis=1)                    # (br, kp+bn)
-        cg = jnp.concatenate([g_buf, g], axis=1)
-        if W2 > kp + bn:
-            cd = jnp.pad(cd, ((0, 0), (0, W2 - kp - bn)),
-                         constant_values=jnp.inf)
-            cg = jnp.pad(cg, ((0, 0), (0, W2 - kp - bn)),
-                         constant_values=I32MAX)
-        cd, cg = _bitonic_fpairs(cd, cg, idx)
-        return cd[:, :kp], cg[:, :kp]
-
-    d_buf = jnp.full((br, kp), INF, jnp.float32)
-    g_buf = jnp.full((br, kp), I32MAX, jnp.int32)
-    d_buf, g_buf = jax.lax.fori_loop(0, nt, tile, (d_buf, g_buf))
-    od_ref[...] = d_buf
-    og_ref[...] = g_buf
-
-
+@functools.partial(jax.jit, static_argnames=("k", "interpret"))
 def knn_topk(vecs, emb, gid, vtype, create, delete, q_vt, q_ts, k: int, *,
-             block_r: int = 8, interpret: bool = False):
+             interpret: bool = False):
     """Pallas top-k nearest visible entries; see the ref oracle for the
     argument contract.  Returns ``(dist (R, k) f32, gids (R, k) i32)``."""
     R, D = vecs.shape
     N = emb.shape[0]
     kp = _pow2ceil(max(1, k))
-    n2 = max(BN, pl.cdiv(max(1, N), BN) * BN)
-    d2 = max(128, _pow2ceil(max(1, D)))
-    br = min(block_r, max(1, R))
-    r2 = pl.cdiv(R, br) * br
+    kb = max(LANES, kp)                  # running buffer: lane-aligned >= k
+    W = max(2 * kb, min(MERGE_W, _pow2ceil(kb + max(1, N))))
+    bn = W - kb                          # entries per grid step
+    n2 = pl.cdiv(max(1, N), bn) * bn
+    r2 = pl.cdiv(R, SUB) * SUB
 
-    v2 = jnp.pad(vecs.astype(jnp.float32), ((0, r2 - R), (0, d2 - D)))
-    e2 = jnp.pad(emb.astype(jnp.float32), ((0, n2 - N), (0, d2 - D)))
-    # ||e||^2 over the zero-padded feature axis: extra terms are exact +0.0,
-    # so this matches the ref's unpadded sum bit-for-bit
-    ee = jnp.sum(e2 * e2, axis=1)[None, :]
-    g2 = jnp.pad(gid, (0, n2 - N), constant_values=-1)[None, :]
-    vt2 = jnp.pad(vtype, (0, n2 - N), constant_values=-1)[None, :]
-    cr2 = jnp.pad(create, (0, n2 - N), constant_values=I32MAX)[None, :]
-    dl2 = jnp.pad(delete, (0, n2 - N), constant_values=0)[None, :]
+    emb = emb.astype(jnp.float32)
+    v2 = jnp.pad(vecs.astype(jnp.float32), ((0, r2 - R), (0, 0)))
+    e2 = jnp.pad(emb.T, ((0, 0), (0, n2 - N)))
+    ee = jax.lax.bitcast_convert_type(sq_norms(emb), jnp.int32)
+    meta = jnp.stack([jnp.pad(gid, (0, n2 - N), constant_values=-1),
+                      jnp.pad(vtype, (0, n2 - N), constant_values=-1),
+                      jnp.pad(create, (0, n2 - N), constant_values=I32MAX),
+                      jnp.pad(delete, (0, n2 - N), constant_values=0),
+                      jnp.pad(ee, (0, n2 - N))]
+                     + [jnp.zeros((n2,), jnp.int32)] * (SUB - 5))
     qvt2 = jnp.pad(q_vt, (0, r2 - R), constant_values=-2)[:, None]
     qts2 = jnp.pad(q_ts, (0, r2 - R), constant_values=0)[:, None]
 
-    row = lambda r: (r, 0)
-    full = lambda r: (0, 0)
+    row = lambda r, n: (r, 0)
+    col = lambda r, n: (0, n)
     od, og = pl.pallas_call(
-        functools.partial(_knn_kernel, kp=kp, bn=BN, nt=n2 // BN, d2=d2),
-        grid=(pl.cdiv(r2, br),),
-        in_specs=[pl.BlockSpec((br, d2), row),      # queries
-                  pl.BlockSpec((n2, d2), full),     # embeddings
-                  pl.BlockSpec((1, n2), full),      # ||e||^2
-                  pl.BlockSpec((1, n2), full),      # gid
-                  pl.BlockSpec((1, n2), full),      # vtype
-                  pl.BlockSpec((1, n2), full),      # create ts
-                  pl.BlockSpec((1, n2), full),      # delete ts
-                  pl.BlockSpec((br, 1), row),       # query vtype
-                  pl.BlockSpec((br, 1), row)],      # query snapshot ts
-        out_specs=[pl.BlockSpec((br, kp), row),
-                   pl.BlockSpec((br, kp), row)],
-        out_shape=[jax.ShapeDtypeStruct((r2, kp), jnp.float32),
-                   jax.ShapeDtypeStruct((r2, kp), jnp.int32)],
+        functools.partial(_knn_kernel, kp=kp, kb=kb),
+        grid=(r2 // SUB, n2 // bn),
+        in_specs=[pl.BlockSpec((SUB, D), row),        # queries
+                  pl.BlockSpec((D, bn), col),         # embeddings, transposed
+                  pl.BlockSpec((SUB, bn), col),       # per-entry metadata
+                  pl.BlockSpec((SUB, 1), row),        # query vtype
+                  pl.BlockSpec((SUB, 1), row)],       # query snapshot ts
+        out_specs=[pl.BlockSpec((SUB, kb), row),
+                   pl.BlockSpec((SUB, kb), row)],
+        out_shape=[jax.ShapeDtypeStruct((r2, kb), jnp.float32),
+                   jax.ShapeDtypeStruct((r2, kb), jnp.int32)],
+        scratch_shapes=[pltpu.VMEM((SUB, W), jnp.float32),
+                        pltpu.VMEM((SUB, W), jnp.int32)],
         interpret=interpret,
-    )(v2, e2, ee, g2, vt2, cr2, dl2, qvt2, qts2)
-    if kp < k:  # unreachable (kp = pow2ceil(k) >= k); keep the slice honest
-        raise AssertionError("kp < k")
+    )(v2, e2, meta, qvt2, qts2)
     return od[:R, :k], og[:R, :k]

@@ -6,11 +6,20 @@ each query the ``k`` nearest *visible* entries of the requested vertex type.
 
 Distance is the gid-monotone surrogate ``||e||^2 - 2 <v, e>`` (the query's
 own ``||v||^2`` term is constant per row and dropped), so values can be
-negative.  Ties are broken by ascending gid via a two-key sort, which makes
+negative.  Both norms and inner products are f32 sums accumulated one
+feature at a time, in feature order (:func:`sq_norms`,
+:func:`inner_products`), never a matmul, and each product enters the sum as
+four partial products that f32 holds exactly (:func:`_madd`).  A compiler
+may fuse any multiply-add into an FMA or not, in either program, without
+changing a bit, so the pallas kernel — which runs the same helpers on its
+tiles — and this oracle agree exactly on any backend, whatever precision
+or blocking a matmul would pick there.  Ties are broken by ascending gid via a two-key sort, which makes
 the selection deterministic and backend-independent.  Invalid slots come
 back as ``(+inf, I32MAX)``.
 """
 from __future__ import annotations
+
+import functools
 
 import jax
 import jax.numpy as jnp
@@ -21,6 +30,43 @@ import jax.numpy as jnp
 I32MAX = 2**31 - 1
 
 
+def _split(x):
+    """``x == hi + lo`` exactly, each with at most 12 significant bits."""
+    bits = jax.lax.bitcast_convert_type(x, jnp.int32) & -4096
+    hi = jax.lax.bitcast_convert_type(bits, jnp.float32)
+    return hi, x - hi
+
+
+def _madd(acc, a, b):
+    """``acc + a*b`` with ``a*b`` added as four exact partial products
+    (12 x 12 significant bits fit f32's 24), so fusing any step into an
+    FMA cannot change the result."""
+    ah, al = _split(a)
+    bh, bl = _split(b)
+    for p in (ah * bh, ah * bl, al * bh, al * bl):
+        acc = acc + p
+    return acc
+
+
+@jax.jit
+def sq_norms(emb):
+    """``||e||^2`` per row of (N, D) f32, accumulated in feature order."""
+    ee = jnp.zeros(emb.shape[:1], jnp.float32)
+    for d in range(emb.shape[1]):
+        ee = _madd(ee, emb[:, d], emb[:, d])
+    return ee
+
+
+def inner_products(vecs, emb_t):
+    """(R, D) x (D, N) -> (R, N) f32 ``<v, e>``, accumulated in feature
+    order (one broadcast multiply-add per feature)."""
+    ip = jnp.zeros((vecs.shape[0], emb_t.shape[1]), jnp.float32)
+    for d in range(vecs.shape[1]):
+        ip = _madd(ip, vecs[:, d:d + 1], emb_t[d:d + 1, :])
+    return ip
+
+
+@functools.partial(jax.jit, static_argnames="k")
 def knn_topk(vecs, emb, gid, vtype, create, delete, q_vt, q_ts, k: int):
     """Top-k nearest visible entries per query row.
 
@@ -40,8 +86,8 @@ def knn_topk(vecs, emb, gid, vtype, create, delete, q_vt, q_ts, k: int):
     R = vecs.shape[0]
     vecs = vecs.astype(jnp.float32)
     emb = emb.astype(jnp.float32)
-    ee = jnp.sum(emb * emb, axis=1)  # (N,)
-    ip = jnp.dot(vecs, emb.T, preferred_element_type=jnp.float32)  # (R, N)
+    ee = sq_norms(emb)                       # (N,)
+    ip = inner_products(vecs, emb.T)         # (R, N)
     ok = (
         (gid >= 0)[None, :]
         & (vtype[None, :] == q_vt[:, None])

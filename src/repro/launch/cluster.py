@@ -9,11 +9,13 @@ continuations) and answer frame-encoded requests.
 **The shared-store seam** (workers must not duplicate the CSR/index
 arrays — the contract ``core/README.md`` documents):
 
-  * ``mode="inproc"`` — the fleet shares ONE ``GraphDB`` object rehydrated
-    via ``FastRestartCache.restart``: every coordinator literally maps the
-    same host/device buffers, writes are fleet-visible immediately, and
-    chaos schedules are deterministic.  This is the default and the mode
-    the acceptance contract (mixed read/write/nearest traffic) runs in.
+  * ``mode="inproc"`` — the fleet shares the caller's ONE ``GraphDB``
+    object: every coordinator reads and writes the same device buffers (no
+    host copy, no second device copy — at a chip-sized store that copy is
+    the difference between fitting and running out of memory), writes are
+    fleet-visible immediately, and chaos schedules are deterministic.  This
+    is the default and the mode the acceptance contract (mixed
+    read/write/nearest traffic) runs in.
   * ``mode="process"`` — the frontend ``export_shared``-publishes the held
     slot as one POSIX shared-memory segment and spawns real worker
     processes that ``attach_shared``-map the same pages (one host copy of
@@ -24,7 +26,10 @@ arrays — the contract ``core/README.md`` documents):
     every replica, which tail-replays them at the ORIGINAL commit
     timestamps — MVCC snapshots and physical gids agree fleet-wide, and a
     read routed to any alive coordinator sees an acked write within the
-    advertised replication lag (``/stats``).
+    advertised replication lag (``/stats``).  Every worker opens the JAX
+    backend, so this mode runs on the CPU backend only: on an accelerator
+    one process holds the chip and a spawned worker would fail or hang,
+    and the constructor refuses.
 
 **Membership, epochs, failover** (:mod:`repro.core.membership`).  The
 frontend is the configuration manager: every worker holds a heartbeat
@@ -71,6 +76,7 @@ import time
 import uuid
 from typing import Optional
 
+import jax
 import numpy as np
 
 from repro.core import faults as faults_mod
@@ -420,11 +426,17 @@ class A1Frontend:
                  **server_kw):
         if mode not in ("inproc", "process"):
             raise ValueError(f"unknown mode {mode!r}")
+        if mode == "process" and jax.default_backend() != "cpu":
+            raise RuntimeError(
+                f"mode='process' spawns {n_workers} coordinator processes "
+                "that each open the JAX backend; on "
+                f"{jax.default_backend()!r} one process holds the "
+                "accelerator, so the workers would fail or hang — serve "
+                "with mode='inproc' (one process, one store)")
         self.mode = mode
         self.name = name
         self.budget_ms = budget_ms
         self.cache = cache or FastRestartCache()
-        self.cache.hold(name, db)
         self.workers: dict[int, object] = {}
         self.stats = {"routed_queries": 0, "routed_writes": 0,
                       "continuation_routes": 0, "stale_routes": 0,
@@ -444,9 +456,9 @@ class A1Frontend:
         self._shipped_seq = 0                   # durable/replicated frontier
         self._waves: dict[int, dict] = {}       # CM-held WAL tail (process)
         if mode == "inproc":
-            # ONE rehydrated GraphDB: every coordinator wraps the same
-            # store object — zero array duplication, writes fleet-visible
-            self.db = self.cache.restart(name)
+            # the caller's GraphDB: every coordinator wraps the same store
+            # object — zero array duplication, writes fleet-visible
+            self.db = db
             self.rlog: Optional[ReplicationLog] = None
             self.membership = Membership(
                 range(n_workers), lease_s=lease_s,
@@ -465,6 +477,7 @@ class A1Frontend:
             import multiprocessing as mp
             # one host copy in shared memory; workers map the same pages.
             # spawn, not fork: jax state does not survive a fork
+            self.cache.hold(name, db)
             self._manifest = self.cache.export_shared(name)
             self.db = _PinBoard()               # pins + faults, no arrays
             self.membership = Membership(

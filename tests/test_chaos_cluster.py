@@ -22,6 +22,7 @@ import pytest
 
 from repro.core.faults import FaultInjector
 from repro.core.query.executor import QueryCaps
+from repro.core.recovery import FastRestartCache
 from repro.launch.cluster import A1Frontend
 
 from test_backend_parity import q_chain
@@ -31,9 +32,14 @@ CAPS = QueryCaps(frontier=128, expand=512, results=8)
 
 
 def mk_fleet(db, n=3, **kw):
+    """A fleet over its own rehydrated copy of ``db``: an inproc fleet
+    serves and writes the very store it is given, and the module fixture
+    is shared by many fleets."""
     kw.setdefault("caps", CAPS)
     kw.setdefault("page_size", 2)
-    return A1Frontend(db, n, **kw)
+    cache = FastRestartCache()
+    cache.hold("fixture", db)
+    return A1Frontend(cache.restart("fixture"), n, **kw)
 
 
 def paginate(fe, on_page=None):

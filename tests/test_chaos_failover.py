@@ -27,6 +27,7 @@ import pytest
 
 from repro.core.faults import FaultInjector
 from repro.core.query.executor import QueryCaps
+from repro.core.recovery import FastRestartCache
 from repro.core.writes import CreateEdge
 from repro.launch.cluster import A1Frontend
 
@@ -38,8 +39,13 @@ COUNT_DOC = q_chain(323, direction="in")          # films of actor 323
 
 
 def mk_fleet(db, n=3, **kw):
+    """A fleet over its own rehydrated copy of ``db``: an inproc fleet
+    serves and writes the very store it is given, and the module fixture
+    is shared by many fleets."""
     kw.setdefault("caps", CAPS)
-    return A1Frontend(db, n, **kw)
+    cache = FastRestartCache()
+    cache.hold("fixture", db)
+    return A1Frontend(cache.restart("fixture"), n, **kw)
 
 
 def unlinked_films(db, actor_key=323):

@@ -248,6 +248,31 @@ def _worker_query(fe, cid, doc, tries=500):
     raise AssertionError(f"worker {cid} never answered")
 
 
+def test_process_mode_refuses_on_an_accelerator(monkeypatch):
+    """One process holds an accelerator: spawning workers that each open
+    the backend would fail or hang, so the constructor refuses and says
+    why — before it copies or exports anything."""
+    import jax
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    with pytest.raises(RuntimeError, match="mode='inproc'"):
+        A1Frontend(None, 2, mode="process")
+
+
+def test_inproc_fleet_serves_the_callers_store():
+    """No second copy of the store: the fleet wraps the caller's GraphDB
+    and its very device arrays."""
+    import jax
+    from repro.core.addressing import StoreConfig
+    from repro.core.graphdb import GraphDB
+    db = GraphDB(StoreConfig(n_shards=1, cap_v=64, cap_e=256, cap_delta=64,
+                             cap_idx=64, cap_idx_delta=32))
+    leaves = jax.tree.leaves(db.store)
+    with A1Frontend(db, 2, caps=CAPS) as fe:
+        assert fe.db is db
+        assert all(a is b for a, b in zip(jax.tree.leaves(fe.db.store),
+                                          leaves))
+
+
 def test_process_mode_workers_map_one_segment():
     db = busy_db()
     a_gid, found = db.lookup_vertex("actor", 323)
