@@ -6,20 +6,24 @@
 
 One chip: build a ``GraphDB`` at the per-shard capacities of the paper's
 film knowledge graph (``configs/a1_kg.py`` FULL, one shard, full column
-widths), load a seeded film KG through the write path, and serve mixed
-batches through ``A1Server`` — 2-hop counts (Q1), 3-hop counts (Q4), star
-intersections (Q3), one ``Nearest`` probe and one paged select — with
-writes interleaved and one background compaction closed while serving.
-Every served answer must equal the reference backend's answer at the same
-snapshot, a sample of counts and every acknowledged write must match a
-numpy oracle built from the loader's own op records, and one fused program
-must contain the Pallas kernels (``tpu_custom_call``).
+widths), load a seeded film KG through the write path, and serve through
+``A1Server`` one mixed batch — 2-hop counts (Q1), 3-hop counts (Q4), star
+intersections (Q3), and point reads of the films and actors the writes
+touch — plus one ``Nearest`` probe and one paged select, with writes
+(``UpdateVertex``, ``CreateEdge``) interleaved and one background
+compaction closed while serving.  Every served answer must equal the
+reference backend's answer at the same snapshot, every count and every
+acknowledged write must match a numpy oracle built from the loader's own
+op records, and one fused program must contain the Pallas kernels
+(``tpu_custom_call``).
 
-``--chips 4`` builds the same KG on four shards with the same per-shard
-capacities, the store placed across the four chips, and serves it through
-``A1Server(use_spmd=True)`` in both budget modes — the per-query answers
-against the reference backend, the shared-budget answers against those at
-the same snapshot, both against the oracle; it runs no other phase.
+``--chips 4`` builds the KG on four shards with the same per-shard
+capacities, the store placed across the four chips and kept there through
+write waves and compactions, and serves the mixed batch through
+``A1Server(use_spmd=True)`` in both budget modes — per-query answers
+against the reference backend, shared-budget counts against the per-query
+ones at the same snapshot, both against the oracle; it runs no other
+phase.
 
 Earlier lines are for orientation only (wall times include compilation).
 The last line is ``{"ok": true, "device": {...}}``.  Any failed phase
@@ -30,6 +34,7 @@ from __future__ import annotations
 
 import argparse
 import dataclasses
+import faulthandler
 import json
 import os
 import sys
@@ -47,7 +52,10 @@ CAP_CUT = 1
 
 
 def log(msg: str) -> None:
+    """Orientation line on stdout, mirrored to stderr so that a run which
+    is stopped from outside still shows how far it got."""
     print(msg, flush=True)
+    print(msg, file=sys.stderr, flush=True)
 
 
 class Clock:
@@ -55,12 +63,16 @@ class Clock:
 
     def __init__(self, jax):
         self.compile_s = 0.0
+        self.n_compiles = 0
         self.phases = {}
         jax.monitoring.register_event_duration_secs_listener(self._on_event)
 
-    def _on_event(self, event: str, secs: float, **_):
+    def _on_event(self, event: str, secs: float, fun_name=None, **_):
         if event == "/jax/core/compile/backend_compile_duration":
             self.compile_s += secs
+            self.n_compiles += 1
+            if secs >= 10:
+                log(f"  compiled {fun_name} in {secs:.1f}s")
 
     def phase(self, name: str):
         clock = self
@@ -68,6 +80,7 @@ class Clock:
         class _Span:
             def __enter__(self):
                 self.t0, self.c0 = time.perf_counter(), clock.compile_s
+                self.n0 = clock.n_compiles
                 log(f"[{name}] ...")
 
             def __exit__(self, *exc):
@@ -75,7 +88,8 @@ class Clock:
                 dc = clock.compile_s - self.c0
                 clock.phases[name] = round(dt, 3)
                 if exc[0] is None:
-                    log(f"[{name}] {dt:.1f}s wall, {dc:.1f}s of it compiling")
+                    log(f"[{name}] {dt:.1f}s wall, {dc:.1f}s of it "
+                        f"compiling {clock.n_compiles - self.n0} programs")
         return _Span()
 
 
@@ -204,9 +218,15 @@ def load_kg(db, scale: int, seed: int):
     oracle = Oracle()
     write = db.write
 
+    shown = [time.perf_counter()]
+
     def recording_write(ops, **kw):
         res = write(ops, **kw)
         oracle.record(ops, res)
+        if time.perf_counter() - shown[0] > 30:
+            shown[0] = time.perf_counter()
+            log(f"  {len(oracle.key)} vertices written, "
+                f"{shown[0] - t0:.0f}s")
         return res
 
     db.write = recording_write
@@ -242,32 +262,63 @@ def same(a, b, what):
             raise AssertionError(f"{what}: rows[{k}] differ")
 
 
-def serve_and_check(server, db, batch, oracle_counts, what):
+def serve_and_check(server, db, probe, what):
     """One served batch at a pinned snapshot, checked against the ref
-    backend at that snapshot and (for the counts given) the oracle."""
+    backend at that snapshot and against the oracle."""
     import numpy as np
     ts = db.snapshot_ts()
-    res = server.execute(batch, qclass=what, read_ts=ts)
+    res = server.execute(probe.batch, qclass=what, read_ts=ts)
     mesh = server.mesh if server.use_spmd else None
-    ref = db.query(batch, caps=server.caps, read_ts=ts, mesh=mesh,
+    ref = db.query(probe.batch, caps=server.caps, read_ts=ts, mesh=mesh,
                    budget=server.budget, backend="ref")
     same(res, ref, what)
     if res.failed_q is not None and np.any(res.failed_q):
         raise AssertionError(f"{what}: {int(np.sum(res.failed_q))} queries "
                              "fast-failed inside the caps")
-    for i, want in oracle_counts.items():
-        if int(res.counts[i]) != want:
-            raise AssertionError(f"{what}: query {i} counted "
-                                 f"{int(res.counts[i])}, oracle {want}")
+    probe.check(res, what)
     return res
 
 
-def count_batch(oracle, dirs, acts):
-    batch = ([q1(d) for d in dirs] + [q4(a) for a in acts]
-             + [q3(d, a) for d, a in zip(dirs, acts)])
-    want = [oracle.q1(d) for d in dirs] + [oracle.q4(a) for a in acts] + [
-        oracle.q3(d, a) for d, a in zip(dirs, acts)]
-    return batch, dict(enumerate(want))
+class Probe:
+    """One mixed batch — Q1/Q4/Q3 counts, the gross of some films and the
+    films of some actors — and what the oracle says it must answer.  The
+    films and actors are those the write phase touches, so the same batch
+    reads every acknowledged write back."""
+
+    def __init__(self, oracle, dirs=(), acts=(), films=(), actors=(),
+                 extra=()):
+        self.oracle, self.films, self.actors = oracle, list(films), actors
+        self.batch = ([q1(d) for d in dirs] + [q4(a) for a in acts]
+                      + [q3(d, a) for d, a in zip(dirs, acts)])
+        self.count_want = lambda: (
+            [oracle.q1(d) for d in dirs] + [oracle.q4(a) for a in acts]
+            + [oracle.q3(d, a) for d, a in zip(dirs, acts)])
+        self.n = len(self.batch)
+        self.batch += [gross_of(oracle.key[oracle.film_dir[f]],
+                                oracle.key[f]) for f in self.films]
+        self.batch += [films_of(oracle.key[a]) for a in actors]
+        self.batch += list(extra)           # checked against ref only
+
+    def check(self, res, what):
+        import numpy as np
+        o, n, nf = self.oracle, self.n, len(self.films)
+        got = np.asarray(res.counts[:n] if n else [], np.int64)
+        want = np.asarray(self.count_want(), np.int64)
+        if not np.array_equal(got, want):
+            i = int(np.flatnonzero(got != want)[0])
+            raise AssertionError(f"{what}: query {i} counted {got[i]}, "
+                                 f"oracle {want[i]}")
+        if nf:
+            (col,) = [v for k, v in res.rows.items() if k[0] == "f32"]
+            if not np.array_equal(col[n:n + nf, 0], np.float32(
+                    [o.gross[f] for f in self.films])):
+                raise AssertionError(f"{what}: a film's gross differs "
+                                     "from the last acknowledged write")
+        for j, a in enumerate(self.actors):
+            row = res.rows_gid[n + nf + j]
+            if set(row[row >= 0].tolist()) != o.actor_films[a]:
+                raise AssertionError(f"{what}: actor {a}'s films differ "
+                                     "from the acknowledged edges")
 
 
 def check_kernels_in_program(db, server, batch):
@@ -296,31 +347,33 @@ def check_kernels_in_program(db, server, batch):
         f"(jax {jax.__version__})")
 
 
-def write_and_read_back(server, db, oracle, dirs, acts, rng):
-    """Writes through the server (gross updates, then new cast edges),
-    each acknowledged one read back through served queries; returns the
-    count batch and its oracle counts after the writes."""
+def plan_writes(oracle, dirs, acts, caps, rng, n=8):
+    """Gross updates of films by the first directors, and new cast edges
+    from those films to actors whose films fit one result window (so the
+    edge reads back in one select row)."""
     import numpy as np
-    from repro.core.writes import CreateEdge, UpdateVertex
     films = sorted(set().union(*[oracle.dir_films[oracle.gid[
-        ("director", d)]] for d in dirs[:8]]))[:8]
+        ("director", d)]] for d in dirs[:n]]))[:n]
     new_gross = {f: float(np.float32(rng.uniform(1, 500))) for f in films}
-    # new cast edges go to actors whose films fit one result window, so
-    # the edge reads back in a single select
-    few = [a for a in acts[8:] if len(oracle.actor_films.get(
-        oracle.gid[("actor", a)], ())) < server.caps.results]
-    new_edges = []
-    for f, a in zip(films, few):
-        ag = oracle.gid[("actor", a)]
-        if ag not in oracle.film_actors.get(f, ()):
-            new_edges.append((f, ag))
+    few = [oracle.gid[("actor", a)] for a in acts[n:]
+           if len(oracle.actor_films.get(oracle.gid[("actor", a)], ()))
+           < caps.results]
+    new_edges = [(f, a) for f, a in zip(films, few)
+                 if a not in oracle.film_actors.get(f, ())]
+    return new_gross, new_edges
+
+
+def write(server, oracle, new_gross, new_edges):
+    """Submit the writes through the server, require every one to be
+    acknowledged, then fold them into the oracle."""
+    from repro.core.writes import CreateEdge, UpdateVertex
     # two waves: an edge create reads its endpoints, so in one wave with an
     # update of the same film it would lose OCC (first wins)
     waves = [[UpdateVertex(f, "film", {"gross": g})
               for f, g in new_gross.items()],
              [CreateEdge(f, a, "film.actor") for f, a in new_edges]]
     n_acks = 0
-    for ops in waves:
+    for ops in filter(None, waves):
         wids = [server.submit_write([op]) for op in ops]
         server.flush_writes()
         for w in wids:
@@ -331,24 +384,8 @@ def write_and_read_back(server, db, oracle, dirs, acts, rng):
     for f, a in new_edges:
         oracle.add_edge(f, a, "film.actor")
     oracle.gross.update(new_gross)
-    batch, want = count_batch(oracle, dirs, acts)
-    serve_and_check(server, db, batch, want, "Q1/Q4/Q3 after writes")
-    reads = [gross_of(oracle.key[oracle.film_dir[f]], oracle.key[f])
-             for f in films]
-    res = serve_and_check(server, db, reads, {}, "read-back")
-    (got,) = res.rows.values()              # the one selected column
-    if not np.array_equal(got[:, 0],
-                          np.float32([new_gross[f] for f in films])):
-        raise AssertionError("updated gross does not read back")
-    res = serve_and_check(server, db, [films_of(oracle.key[a])
-                                       for _, a in new_edges], {},
-                          "edge read-back")
-    for i, (f, _) in enumerate(new_edges):
-        if f not in set(res.rows_gid[i].tolist()):
-            raise AssertionError(f"new edge to film {f} does not read back")
-    log(f"{n_acks} acknowledged writes read back "
-        f"({len(new_gross)} updates, {len(new_edges)} edges)")
-    return batch, want
+    log(f"{n_acks} writes acknowledged ({len(new_gross)} updates, "
+        f"{len(new_edges)} edges)")
 
 
 def one_chip(jax, clock, args):
@@ -374,16 +411,18 @@ def one_chip(jax, clock, args):
     caps = QueryCaps(**_QCAPS)
     rng = np.random.default_rng(args.seed)
     dirs, acts = pick_keys(kg, oracle, caps.expand // 2, rng, 16)
+    new_gross, new_edges = plan_writes(oracle, dirs, acts, caps, rng)
+    probe = Probe(oracle, dirs, acts, new_gross, [a for _, a in new_edges])
     server = A1Server(db, caps=caps, budget="per-query", budget_ms=1e9,
                       write_batch=64)
-    batch, want = count_batch(oracle, dirs, acts)
 
-    with clock.phase("serve: counts + nearest + paged select"):
-        serve_and_check(server, db, batch, want, "Q1/Q4/Q3")
+    with clock.phase("serve: mixed batch + nearest + paged select"):
+        serve_and_check(server, db, probe, "mixed")
         vec = [float(rng.uniform(1, 500))] + [0.0] * (cfg.d_f32 - 1)
         near = [{"nearest": {"type": "film", "vector": vec, "k": 8},
                  "select": ["key"]}]
-        res = serve_and_check(server, db, near, {}, "nearest")
+        res = serve_and_check(server, db, Probe(oracle, extra=near),
+                              "nearest")
         if int(np.sum(res.rows_gid[0] >= 0)) != 8:
             raise AssertionError("nearest returned fewer than k films")
         # an actor whose films fill more than one page but one result window
@@ -405,17 +444,18 @@ def one_chip(jax, clock, args):
         log(f"paged select: {len(got)} rows in pages of {server.page}")
 
     with clock.phase("serve: interleaved writes"):
-        batch, want = write_and_read_back(server, db, oracle, dirs, acts,
-                                          rng)
+        write(server, oracle, new_gross, new_edges)
 
     with clock.phase("serve: background compaction"):
+        # the shadow is built while the store serves; the probe reads
+        # every acknowledged write back during and after the handoff
         handle = db.begin_compaction(("edges", "index"))
-        serve_and_check(server, db, batch, want, "during compaction")
+        serve_and_check(server, db, probe, "during compaction")
         done = db.try_handoff(handle)
         if not all(done.values()):
             raise AssertionError(f"compaction handoff refused: {done}")
-        serve_and_check(server, db, batch, want, "after compaction")
-        check_kernels_in_program(db, server, batch)
+        serve_and_check(server, db, probe, "after compaction")
+        check_kernels_in_program(db, server, probe.batch)
     return dev, 1
 
 
@@ -455,38 +495,45 @@ def four_chips(jax, clock, args):
     rng = np.random.default_rng(args.seed)
     # routed frontiers must fit the per-destination buckets as well
     dirs, acts = pick_keys(kg, oracle, caps.bucket, rng, 16)
-    batch, want = count_batch(oracle, dirs, acts)
+    new_gross, new_edges = plan_writes(oracle, dirs, acts, caps, rng)
+    probe = Probe(oracle, dirs, acts, new_gross, [a for _, a in new_edges])
     with clock.phase("serve: per-query budget, writes"):
         server = A1Server(db, caps=caps, use_spmd=True, mesh=mesh,
                           budget="per-query", budget_ms=1e9, write_batch=64)
-        serve_and_check(server, db, batch, want, "spmd per-query")
-        batch, want = write_and_read_back(server, db, oracle, dirs, acts,
-                                          rng)
-    spread("after writes")
+        serve_and_check(server, db, probe, "spmd per-query")
+        write(server, oracle, new_gross, new_edges)
+        spread("after writes")
+        # fold the writes in, so the next batch runs the programs above
+        db.run_compaction()
+        db.run_index_compaction()
+        spread("after compaction")
+        serve_and_check(server, db, probe, "spmd per-query after writes")
     with clock.phase("serve: shared budget"):
-        # the per-query answer at this snapshot was just checked against
-        # the ref backend; the shared pool must give the same answer
-        # (it may differ only by fast-fail flags, and none is allowed)
+        # per-query answers were just checked against the ref backend; the
+        # shared pool must give the same counts at the same snapshot (it
+        # may differ only by fast-fail flags, and none is allowed)
+        counts = Probe(oracle, dirs, acts)
         ts = db.snapshot_ts()
-        per_query = server.execute(batch, read_ts=ts)
+        per_query = server.execute(counts.batch, read_ts=ts)
         shared = A1Server(db, caps=caps, use_spmd=True, mesh=mesh,
                           budget="shared", budget_ms=1e9)
-        res = shared.execute(batch, read_ts=ts)
+        res = shared.execute(counts.batch, read_ts=ts)
         same(res, per_query, "spmd shared")
-        for i, w in want.items():
-            if int(res.counts[i]) != w:
-                raise AssertionError(f"spmd shared: query {i} counted "
-                                     f"{int(res.counts[i])}, oracle {w}")
+        counts.check(res, "spmd shared")
     return jax.devices()[0], 4
 
 
 def main(argv=None) -> int:
     ap = argparse.ArgumentParser(description=__doc__.split("\n\n")[0])
     ap.add_argument("--chips", type=int, default=1, choices=(1, 4))
-    ap.add_argument("--scale", type=int, default=10_000,
+    ap.add_argument("--scale", type=int, default=2_000,
                     help="KG size: scale x (10 films, 15 actors, 2 directors)")
     ap.add_argument("--seed", type=int, default=0)
+    ap.add_argument("--deadline", type=float, default=1140.0,
+                    help="seconds after which every thread's stack is "
+                         "dumped to stderr and the run exits non-zero")
     args = ap.parse_args(argv)
+    faulthandler.dump_traceback_later(args.deadline, exit=True)
     try:
         import jax
         from repro.launch import jax_cache
@@ -520,6 +567,7 @@ def main(argv=None) -> int:
             f"peak_bytes_in_use={st.get('peak_bytes_in_use')}")
     log(f"total {time.perf_counter() - t0:.1f}s, compiling "
         f"{clock.compile_s:.1f}s; phases {json.dumps(clock.phases)}")
+    faulthandler.cancel_dump_traceback_later()
     print(json.dumps({"ok": True, "device": {
         "platform": dev.platform, "kind": dev.device_kind,
         "count": len(jax.devices())}}))

@@ -14,6 +14,7 @@ query can see them), and rebuild the per-slot offsets.
 from __future__ import annotations
 
 from functools import partial
+from typing import Optional
 
 import jax
 import jax.numpy as jnp
@@ -21,7 +22,7 @@ import jax.numpy as jnp
 from repro.core import backend as backend_mod
 from repro.core.addressing import NULL, TS_INF, StoreConfig
 from repro.core.merge import merge_runs
-from repro.core.store import GraphStore, visible
+from repro.core.store import GraphStore, visible, window_shard_major
 
 ANY_TYPE = jnp.int32(-1)
 
@@ -96,7 +97,8 @@ def _delta_arrays(store: GraphStore, direction: str):
 
 def expand(store: GraphStore, cfg: StoreConfig, qids, gids, valid, *,
            etype, direction: str, read_ts, cap_out: int,
-           backend: backend_mod.Backend = backend_mod.REF):
+           backend: backend_mod.Backend = backend_mod.REF,
+           dwin: Optional[int] = None):
     """Enumerate edges of ``gids`` (global-array mode).
 
     Args:
@@ -107,10 +109,13 @@ def expand(store: GraphStore, cfg: StoreConfig, qids, gids, valid, *,
       cap_out: static capacity for the CSR expansion segment.
       backend: read-path backend; the pallas path streams spans through the
         edge_expand kernel and produces bit-identical output (same layout).
+      dwin: static per-shard delta-log window (``planner.delta_window``):
+        only the filled prefix of each shard's log is matched against the
+        frontier; None scans the whole log.
 
     Returns:
       (out_qids, out_nbr, out_valid, overflow): the expansion, shape
-      (cap_out + F*cap_delta_scan,), plus a bool overflow flag (fast-fail).
+      (cap_out + F*S*dwin,), plus a bool overflow flag (fast-fail).
     """
     S, cap_v, cap_e = cfg.n_shards, cfg.cap_v, cfg.cap_e
     indptr, nbr, typ, ecre, edel = _csr_arrays(store, direction)
@@ -147,9 +152,11 @@ def expand(store: GraphStore, cfg: StoreConfig, qids, gids, valid, *,
         out_n = jnp.where(e_ok, nbr[epos], NULL)
 
     # ---- tier 2: delta-log merge (recent, not yet compacted edges) --------
-    dslot, dnbr, dtyp, dts, ddel = _delta_arrays(store, direction)
+    W = cfg.cap_delta if dwin is None else min(int(dwin), cfg.cap_delta)
+    dslot, dnbr, dtyp, dts, ddel = window_shard_major(
+        _delta_arrays(store, direction), S, cfg.cap_delta, W)
     D = dslot.shape[0]
-    d_shard = jnp.arange(D, dtype=jnp.int32) // cfg.cap_delta
+    d_shard = jnp.arange(D, dtype=jnp.int32) // W
     d_gid = dslot * S + d_shard                       # gid of the delta's owner
     # match matrix: frontier item x delta entry
     m = (valid[:, None]

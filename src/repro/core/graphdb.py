@@ -36,6 +36,9 @@ from repro.core.writes import CapacityError
 _lookup = jax.jit(index_mod.lookup, static_argnames=("cfg", "backend",
                                                      "xd_win"))
 _headers = jax.jit(gather_headers, static_argnames=("cfg",))
+_data = jax.jit(gather_data, static_argnames=("cfg",))
+_expand = jax.jit(edges_mod.expand, static_argnames=("cfg", "direction",
+                                                     "cap_out"))
 
 
 @functools.lru_cache(maxsize=None)
@@ -304,7 +307,7 @@ class GraphDB:
                   read_ts: Optional[int] = None, etype: int = -1,
                   cap: int = 4096) -> list[tuple[int, int]]:
         rts = self.clock if read_ts is None else read_ts
-        q, n, v, ovf = edges_mod.expand(
+        q, n, v, ovf = _expand(
             self.store, self.cfg,
             jnp.zeros((1,), jnp.int32), jnp.asarray([gid], jnp.int32),
             jnp.asarray([True]), etype=jnp.int32(etype), direction=direction,
@@ -334,8 +337,8 @@ class GraphDB:
             dslot, dtyp, dnbr = st.il_slot, st.il_type, st.il_nbr
         sh, sl = gid % S, gid // S
         start = int(indptr[sh * (cap_v + 1) + sl]) + sh * cap_e
-        k = np.arange(cap)
-        csr_t = np.asarray(typ)[np.minimum(start + k, S * cap_e - 1)]
+        k = jnp.arange(cap, dtype=jnp.int32)
+        csr_t = np.asarray(typ[jnp.minimum(start + k, S * cap_e - 1)])
         D = dslot.shape[0]
         d_shard = np.arange(D) // cfg.cap_delta
         d_gid = np.asarray(dslot) * S + d_shard
@@ -587,7 +590,7 @@ class GraphDB:
         return int(vt[0]), int(key[0]), bool(alive[0])
 
     def _read_data_host(self, gid: int, rts: int):
-        f, i, alive = gather_data(
+        f, i, alive = _data(
             self.store, self.cfg, jnp.asarray([gid], jnp.int32),
             jnp.int32(rts))
         return np.asarray(f[0]), np.asarray(i[0])

@@ -174,7 +174,7 @@ def execute(db, queries: list[dict], *, caps: Optional[QueryCaps] = None,
 def _execute_uniform(db, lowered: list[ir.Lowered], caps: QueryCaps,
                      read_ts: int, be, mesh, storage_axes) -> QueryResult:
     """One plan shape, shared working-set budget: the per-plan executors."""
-    from repro.core.query.planner import index_window
+    from repro.core.query.planner import delta_window, index_window
     plan = lowered[0].plan
     Q = len(lowered)
     xwin = index_window(db)
@@ -190,6 +190,7 @@ def _execute_uniform(db, lowered: list[ir.Lowered], caps: QueryCaps,
         fn = compile_query_spmd(db.cfg, plan, caps, Q, mesh, storage_axes,
                                 backend=be, xwin=xwin)
     else:
-        fn = compile_query(db.cfg, plan, caps, Q, be, xwin=xwin)
+        fn = compile_query(db.cfg, plan, caps, Q, be, xwin=xwin,
+                           dwin=delta_window(db))
     out = fn(db.store, keys, jnp.ones((Q,), bool), jnp.int32(read_ts))
     return _to_result(plan, out)

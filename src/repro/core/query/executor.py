@@ -205,7 +205,7 @@ def build_select(store: GraphStore, cfg: StoreConfig, plan: Plan,
 def _chain_frontier(store, cfg: StoreConfig, plan: Plan, caps: QueryCaps,
                     keys, valid, read_ts,
                     backend: backend_mod.Backend = backend_mod.REF,
-                    xwin: Optional[int] = None):
+                    xwin: Optional[int] = None, dwin: Optional[int] = None):
     """Run index lookup + all hops; returns final (qids, gids, valid, failed)."""
     Q = keys.shape[0]
     F = caps.frontier
@@ -228,7 +228,7 @@ def _chain_frontier(store, cfg: StoreConfig, plan: Plan, caps: QueryCaps,
         oq, on, ov, ovf = edges_mod.expand(
             store, cfg, qids, gids, vmask, etype=jnp.int32(hop.etype),
             direction=hop.direction, read_ts=read_ts, cap_out=caps.expand,
-            backend=backend)
+            backend=backend, dwin=dwin)
         failed = failed | ovf
         qids, gids, vmask, ovf2 = dedup_compact(oq, on, ov, F)
         failed = failed | ovf2
@@ -262,7 +262,7 @@ def _terminal(store, cfg, plan, caps, qids, gids, vmask, read_ts, Q: int):
 def _run_intersect(store, cfg, plan: Plan, caps: QueryCaps, keys_b, valid,
                    read_ts, Q: int,
                    backend: backend_mod.Backend = backend_mod.REF,
-                   xwin: Optional[int] = None):
+                   xwin: Optional[int] = None, dwin: Optional[int] = None):
     """Star-pattern intersection (Q3): keep vertices reached by all branches."""
     B = len(plan.branches)
     all_q, all_g, all_v = [], [], []
@@ -270,7 +270,7 @@ def _run_intersect(store, cfg, plan: Plan, caps: QueryCaps, keys_b, valid,
     for bi, branch in enumerate(plan.branches):
         q, g, v, f = _chain_frontier(store, cfg, branch, caps,
                                      keys_b[bi], valid, read_ts, backend,
-                                     xwin)
+                                     xwin, dwin)
         failed = failed | f
         all_q.append(q)
         all_g.append(g)
@@ -299,13 +299,14 @@ CACHE_STATS = {"hits": 0, "misses": 0}
 def compile_query(cfg: StoreConfig, plan: Plan, caps: QueryCaps,
                   n_queries: int,
                   backend: backend_mod.Backend = backend_mod.REF,
-                  xwin: Optional[int] = None):
+                  xwin: Optional[int] = None, dwin: Optional[int] = None):
     """Build the jitted program for one plan shape (shared-budget batch).
 
-    ``xwin`` is the static primary-index delta window (see
-    ``planner.index_window``) — semantics-preserving (skipped slots are
-    provably empty), part of the cache key like the planner's ``dwin``."""
-    key = (cfg, plan, caps, n_queries, backend, xwin, "local")
+    ``xwin``/``dwin`` are the static primary-index / edge delta windows
+    (see ``planner.index_window`` / ``planner.delta_window``) —
+    semantics-preserving (skipped slots are provably empty), part of the
+    cache key like the planner's."""
+    key = (cfg, plan, caps, n_queries, backend, xwin, dwin, "local")
     if key in _CACHE:
         CACHE_STATS["hits"] += 1
         return _CACHE[key]
@@ -316,14 +317,15 @@ def compile_query(cfg: StoreConfig, plan: Plan, caps: QueryCaps,
         def run(store, keys_b, valid, read_ts):
             out, failed = _run_intersect(store, cfg, plan, caps, keys_b,
                                          valid, read_ts, n_queries, backend,
-                                         xwin)
+                                         xwin, dwin)
             out["failed"] = failed
             return out
     else:
         @jax.jit
         def run(store, keys, valid, read_ts):
             q, g, v, failed = _chain_frontier(store, cfg, plan, caps, keys,
-                                              valid, read_ts, backend, xwin)
+                                              valid, read_ts, backend, xwin,
+                                              dwin)
             out = _terminal(store, cfg, plan, caps, q, g, v, read_ts,
                             n_queries)
             out["failed"] = failed

@@ -71,16 +71,17 @@ def _backfill(db, vtid: int) -> None:
     cr = np.asarray(db.store.v_create)
     dl = np.asarray(db.store.v_delete)
     dts = np.asarray(db.store.vdata_ts)
-    vdf = np.asarray(db.store.vdata_f)
     now = db.clock
     rows = np.where((vtypes == vtid) & (cr <= now) & (now < dl))[0]
+    # only the indexed rows' payloads cross to the host
+    vdf = np.asarray(db.store.vdata_f[jnp.asarray(rows, jnp.int32)])
     appends = []
-    for row in rows:
+    for row, emb in zip(rows, vdf):
         shard, slot = int(row) // cfg.cap_v, int(row) % cfg.cap_v
         gid = slot * cfg.n_shards + shard
         pos = _alloc(db, gid)
         db._vx_pos[gid] = (pos, vtid)
-        appends.append((pos, gid, vtid, int(max(cr[row], dts[row])), vdf[row]))
+        appends.append((pos, gid, vtid, int(max(cr[row], dts[row])), emb))
     _device_apply(db, appends, [], 0)
 
 

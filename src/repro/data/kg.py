@@ -19,6 +19,30 @@ from repro.core.graphdb import GraphDB
 from repro.core.writes import CreateEdge, CreateVertex
 
 
+def _cdf(weights: np.ndarray) -> np.ndarray:
+    c = np.cumsum(weights)
+    return c / c[-1]
+
+
+def _successive(rng, cdf: np.ndarray, sizes: np.ndarray) -> list:
+    """Per row, ``sizes[r]`` distinct indices drawn one after another with
+    the weights behind ``cdf``, a repeat redrawn — the law of numpy's
+    ``choice(replace=False, p=...)`` without its O(n) work per row."""
+    if len(sizes) and sizes.max() > len(cdf):
+        raise ValueError("more distinct draws than weighted items")
+    m = 4 * int(sizes.max(initial=1))
+    cand = cdf.searchsorted(rng.random((len(sizes), m)), side="right")
+    out = []
+    for row, k in zip(cand.tolist(), sizes.tolist()):
+        picks = list(dict.fromkeys(row))[:k]
+        while len(picks) < k:
+            x = int(cdf.searchsorted(rng.random(), side="right"))
+            if x not in picks:
+                picks.append(x)
+        out.append(picks)
+    return out
+
+
 @dataclasses.dataclass
 class FilmKG:
     db: GraphDB
@@ -87,9 +111,7 @@ def build_film_kg(*, n_films: int = 200, n_actors: int = 300,
 
     # Zipf-skewed popularity: a few mega-actors, like the paper's skew
     pop = 1.0 / np.power(np.arange(1, n_actors + 1), zipf_a)
-    pop /= pop.sum()
     dir_pop = 1.0 / np.power(np.arange(1, n_directors + 1), zipf_a)
-    dir_pop /= dir_pop.sum()
 
     films = load([CreateVertex(
         "film", int(k),
@@ -98,16 +120,18 @@ def build_film_kg(*, n_films: int = 200, n_actors: int = 300,
          "genre": int(rng.integers(n_genres))}) for k in f_keys], 200)
 
     # bulk-load fast path (check=False): uniqueness is the loader's contract
+    n_f = len(films)
+    f_dir = _cdf(dir_pop).searchsorted(rng.random(n_f), side="right")
+    f_genre = rng.integers(n_genres, size=n_f)
+    casts = _successive(rng, _cdf(pop), rng.integers(*actors_per_film,
+                                                     size=n_f))
     e_ops = []
-    for i, f in enumerate(films):
-        d = int(rng.choice(n_directors, p=dir_pop))
+    for f, d, g, cast in zip(films, f_dir.tolist(), f_genre.tolist(),
+                             casts):
         e_ops.append(CreateEdge(dirs[d], f, "film.director", check=False))
-        e_ops.append(CreateEdge(f, genres[int(rng.integers(n_genres))],
-                                "film.genre", check=False))
-        n_cast = int(rng.integers(*actors_per_film))
-        for a in rng.choice(n_actors, size=n_cast, replace=False, p=pop):
-            e_ops.append(CreateEdge(f, acts[int(a)], "film.actor",
-                                    check=False))
+        e_ops.append(CreateEdge(f, genres[g], "film.genre", check=False))
+        e_ops += [CreateEdge(f, acts[a], "film.actor", check=False)
+                  for a in cast]
     load(e_ops, 400)
     db.run_compaction()
     db.run_index_compaction()

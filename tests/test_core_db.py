@@ -223,3 +223,18 @@ def test_catalog_proxy_cache_ttl():
     cat.create_edge_type("x", "g", "e")                 # bump version
     t[0] = 30.0
     assert cat.proxy("x", "g", "v", "v") is vt          # refreshed object
+
+
+def test_loader_draws_distinct_zipf_picks():
+    """The film-KG loader's cast sampler: distinct picks per film, as many
+    as asked, skewed toward the popular end like the weights."""
+    from repro.data.kg import _cdf, _successive
+    w = 1.0 / np.power(np.arange(1, 51), 1.5)
+    sizes = np.random.default_rng(0).integers(2, 8, size=4000)
+    picks = _successive(np.random.default_rng(1), _cdf(w), sizes)
+    assert [len(p) for p in picks] == sizes.tolist()
+    assert all(len(set(p)) == len(p) for p in picks)
+    hits = np.bincount(np.concatenate(picks), minlength=50)
+    assert hits[0] > hits[10] > hits[49]
+    with pytest.raises(ValueError):
+        _successive(np.random.default_rng(0), _cdf(w[:3]), np.array([4]))
